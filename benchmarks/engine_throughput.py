@@ -128,9 +128,10 @@ def build_engine(mode: str, *, prefix_cache: bool | None = None,
     from repro.models import transformer as T
     from repro.runtime.serve import ServeHParams
     from repro.serving import EngineConfig, ServingEngine
+    from repro.launch.mesh import make_mesh
 
     cfg = bench_config()
-    mesh = jax.make_mesh((1, 1), ("data", "model"))
+    mesh = make_mesh((1, 1), ("data", "model"))
     params = T.init(cfg, jax.random.PRNGKey(0))
     # wall=True keeps the engine on real time (time.monotonic) — the
     # streaming wall-clock mode measures seconds, not decode-steps
@@ -590,9 +591,10 @@ def packed_cache_sized_concats() -> int:
     from repro.runtime.paging import make_paged_layout
     from repro.runtime.serve import (ServeHParams, make_kv_cache,
                                      make_layout, make_packed_step)
+    from repro.launch.mesh import make_mesh
 
     cfg = bench_config()
-    mesh = jax.make_mesh((1, 1), ("data", "model"))
+    mesh = make_mesh((1, 1), ("data", "model"))
     params = T.init(cfg, jax.random.PRNGKey(0))
     hp = ServeHParams(decode_mode="exact", ssm_chunk=8)
     base = make_layout(cfg, mesh, N_SLOTS, MAX_CACHE, hp, PREFILL_LEN)

@@ -21,11 +21,12 @@ def main():
     from repro.models import transformer as T
     from repro.runtime.serve import (ServeHParams, make_prefill_step,
                                      make_serve_step)
+    from repro.launch.mesh import make_mesh
 
     if len(jax.devices()) < 8:
         print("set XLA_FLAGS=--xla_force_host_platform_device_count=8")
         sys.exit(1)
-    mesh = jax.make_mesh((2, 4), ("data", "model"))
+    mesh = make_mesh((2, 4), ("data", "model"))
     cfg = get_config("gemma3-1b").reduced()
     params = T.init(cfg, jax.random.PRNGKey(0))
     B, n, gen = 8, 64, 12

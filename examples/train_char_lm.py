@@ -40,13 +40,14 @@ def main():
     from repro.models.config import ModelConfig
     from repro.optim import adamw_init
     from repro.runtime.train import make_train_step, TrainHParams
+    from repro.launch.mesh import make_mesh
 
     data, model = (int(x) for x in args.mesh.split("x"))
     if len(jax.devices()) < data * model:
         print(f"note: {len(jax.devices())} devices < mesh {args.mesh}; "
               "set XLA_FLAGS=--xla_force_host_platform_device_count=8")
         sys.exit(1)
-    mesh = jax.make_mesh((data, model), ("data", "model"))
+    mesh = make_mesh((data, model), ("data", "model"))
 
     tok = CharTokenizer()
     corpus = tok.encode(synthetic_text(1_000_000, seed=1))

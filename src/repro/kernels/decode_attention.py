@@ -199,19 +199,27 @@ def flash_decode_stats(
     _, m_loc, hkv, _ = k.shape
     assert hq % hkv == 0, (hq, hkv)
     grp = hq // hkv
-    block_k = min(block_k, max(8, 1 << (m_loc - 1).bit_length()))
+    # one column tile for both column sets: below 128 lanes it must
+    # span each padded set whole, so size it to the longer of the two
+    m_max = max(m_loc, kz.shape[1] if kz is not None else 0)
+    block_k = min(block_k, max(8, 1 << (m_max - 1).bit_length()))
 
     qk = q[:, 0].reshape(b, hkv, grp, hd)
     kt = _pad_to(k.swapaxes(1, 2), block_k, 2)       # (B,Hkv,M',hd)
     vt = _pad_to(v.swapaxes(1, 2), block_k, 2)
-    validp = _pad_to(valid.astype(jnp.int32), block_k, 1)
+    # per-row vectors ride as (B, 1, M') so each block's last two dims
+    # are (1, block_k): the full extent of the unit axis and a lane-
+    # aligned column tile — a (1, block_k) block over (B, M') is refused
+    # by the TPU compiler once B > 1
+    validp = _pad_to(valid.astype(jnp.int32), block_k, 1)[:, None]
     nk_loc = kt.shape[2] // block_k
 
     has_means = kz is not None
     if has_means:
         kzt = _pad_to(kz.astype(k.dtype).swapaxes(1, 2), block_k, 2)
         vzt = _pad_to(vz.astype(v.dtype).swapaxes(1, 2), block_k, 2)
-        lgz = _pad_to(log_gz.astype(jnp.float32), block_k, 1, value=NEG)
+        lgz = _pad_to(log_gz.astype(jnp.float32), block_k, 1,
+                      value=NEG)[:, None]
         nk_means = kzt.shape[2] // block_k
     else:
         nk_means = 0
@@ -224,7 +232,8 @@ def flash_decode_stats(
         return jnp.clip(ki - nk_loc, 0, max(nk_means - 1, 0))
 
     in_specs = [
-        pl.BlockSpec((1, block_k), lambda bi, h, ki: (bi, loc(ki))),
+        pl.BlockSpec((None, 1, block_k),
+                     lambda bi, h, ki: (bi, 0, loc(ki))),
         pl.BlockSpec((None, None, grp, hd), lambda bi, h, ki: (bi, h, 0, 0)),
         pl.BlockSpec((None, None, block_k, hd),
                      lambda bi, h, ki: (bi, h, loc(ki), 0)),
@@ -234,7 +243,8 @@ def flash_decode_stats(
     args = [validp, qk, kt, vt]
     if has_means:
         in_specs += [
-            pl.BlockSpec((1, block_k), lambda bi, h, ki: (bi, mns(ki))),
+            pl.BlockSpec((None, 1, block_k),
+                         lambda bi, h, ki: (bi, 0, mns(ki))),
             pl.BlockSpec((None, None, block_k, hd),
                          lambda bi, h, ki: (bi, h, mns(ki), 0)),
             pl.BlockSpec((None, None, block_k, hd),

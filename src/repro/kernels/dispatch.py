@@ -15,12 +15,11 @@ Consumed by ``kernels.ops.prism_attention_op`` (prefill),
 ``kernels.segment_means.segment_means_op``,
 ``kernels.decode_attention.flash_decode_stats`` (the serving hot path),
 and plumbed through ``ServeHParams.backend`` / ``launch.serve
---backend``.  ``PRISM_KERNEL_BACKEND`` overrides the default for code
-paths that don't thread the switch explicitly.
+--backend``.  Nothing in the environment changes what ``"auto"``
+resolves to: on a TPU the kernels run compiled unless a caller names
+another backend.
 """
 from __future__ import annotations
-
-import os
 
 import jax
 
@@ -33,13 +32,11 @@ def platform() -> str:
 
 
 def resolve_backend(backend: str | None = None) -> str:
-    """'auto' (or None) -> the PRISM_KERNEL_BACKEND env override if set,
-    else 'pallas' on TPU / 'jnp' elsewhere; explicit 'pallas'/'jnp'
-    always wins over the env.  Raises on anything outside BACKENDS."""
+    """'auto' (or None) -> 'pallas' on TPU / 'jnp' elsewhere; explicit
+    'pallas'/'jnp' are returned as given.  Raises on anything outside
+    BACKENDS."""
     if backend is None:
         backend = "auto"
-    if backend == "auto":
-        backend = os.environ.get("PRISM_KERNEL_BACKEND", "auto")
     if backend not in BACKENDS:
         raise ValueError(
             f"backend {backend!r} not in {BACKENDS}")

@@ -5,11 +5,12 @@ compression with the residual-stream write — on TPU the block output is
 already streaming through VMEM, so computing the means there saves a full
 HBM round-trip over a separate jnp.mean (see EXPERIMENTS.md §Perf).
 
-Grid (L, D/blk_d): each program mean-reduces one (segment × feature-block)
-tile.  Ragged partitions (N_p % L != 0) follow the paper's Eq. 8 split —
-the first L-1 even segments stream through the kernel, the oversized
-last segment is mean-reduced in jnp with one static slice (matching
-`repro.core.segment_means`, which is also the kernel's oracle).
+Grid (B, L, D/blk_d): each program mean-reduces one (segment ×
+feature-block) tile.  Ragged partitions (N_p % L != 0) follow the
+paper's Eq. 8 split — the first L-1 even segments stream through the
+kernel, the oversized last segment is mean-reduced in jnp with one
+static slice (matching `repro.core.segment_means`, which is also the
+kernel's oracle).
 ``interpret=None`` auto-detects the platform (``kernels.dispatch``).
 """
 from __future__ import annotations
@@ -23,7 +24,7 @@ from jax.experimental import pallas as pl
 from .dispatch import default_interpret
 
 
-def _kernel(x_ref, o_ref, *, seg: int):
+def _kernel(x_ref, o_ref):
     o_ref[...] = jnp.mean(
         x_ref[...].astype(jnp.float32), axis=0, keepdims=True
     ).astype(o_ref.dtype)
@@ -47,22 +48,22 @@ def segment_means_op(x, *, L: int, block_d: int = 512,
                                 block_d=block_d, interpret=interpret)
         return jnp.concatenate([head, tail], axis=1)
     seg = n // L
-    block_d = min(block_d, d)
-    if d % block_d:
-        # largest divisor of d keeps the feature-block grid (768 with
-        # the default 512 -> 384); degenerate divisors (prime-ish d)
-        # fall back to one full-width tile
-        div = next(x for x in range(block_d, 0, -1) if d % x == 0)
-        block_d = div if div >= 128 else d
+    # a feature tile narrower than d must be a multiple of the 128-lane
+    # width (768 with the default 512 -> 384); without such a divisor
+    # one full-width tile is the legal choice
+    block_d = next((c for c in range(min(block_d, d), 127, -1)
+                    if d % c == 0 and c % 128 == 0), d)
 
-    def run(x2):          # (N_p, D) -> (L, D)
-        return pl.pallas_call(
-            functools.partial(_kernel, seg=seg),
-            grid=(L, d // block_d),
-            in_specs=[pl.BlockSpec((seg, block_d), lambda l, j: (l, j))],
-            out_specs=pl.BlockSpec((1, block_d), lambda l, j: (l, j)),
-            out_shape=jax.ShapeDtypeStruct((L, d), x2.dtype),
-            interpret=interpret,
-        )(x2)
-
-    return jax.vmap(run)(x)
+    # segments as their own axis, (B, L, seg, D) -> (B, L, 1, D): every
+    # block's last two dims are (whole segment | unit axis, feature tile)
+    out = pl.pallas_call(
+        _kernel,
+        grid=(b, L, d // block_d),
+        in_specs=[pl.BlockSpec((None, None, seg, block_d),
+                               lambda i, l, j: (i, l, 0, j))],
+        out_specs=pl.BlockSpec((None, None, 1, block_d),
+                               lambda i, l, j: (i, l, 0, j)),
+        out_shape=jax.ShapeDtypeStruct((b, L, 1, d), x.dtype),
+        interpret=interpret,
+    )(x.reshape(b, L, seg, d))
+    return out[:, :, 0]

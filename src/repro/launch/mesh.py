@@ -1,5 +1,6 @@
-"""Production mesh definitions (TPU v5e).
+"""Mesh construction: the one place a ``jax.sharding.Mesh`` is built.
 
+Production meshes (TPU v5e):
 Single pod: (16, 16) = 256 chips, axes ("data", "model").
 Multi-pod:  (2, 16, 16) = 512 chips, axes ("pod", "data", "model").
 
@@ -9,6 +10,12 @@ exchange is an all-gather over it.  ``data`` carries batch + FSDP.  ``pod``
 is pure data parallelism — PRISM's sequence exchange never crosses the
 (slow) pod boundary, matching the paper's premise.
 
+Every axis is ``AxisType.Auto``: the runtime places its arrays with
+``NamedSharding`` and hand-written ``shard_map`` specs, and leaves the
+rest to the partitioner.  ``jax.make_mesh`` defaults to Explicit axes,
+under which sharding-in-types rejects the runtime's cache splices, so no
+code calls it without going through :func:`make_mesh`.
+
 Functions, not module constants: importing this module must not touch jax
 device state (the dry-run sets XLA_FLAGS before any jax init).
 """
@@ -17,23 +24,26 @@ from __future__ import annotations
 import jax
 
 
+def make_mesh(shape, axes) -> jax.sharding.Mesh:
+    """A mesh of ``shape`` named ``axes``, every axis Auto.
+    ``jax.make_mesh`` takes the first ``prod(shape)`` devices in its
+    topology-aware order and refuses a shape larger than the host."""
+    return jax.make_mesh(tuple(shape), tuple(axes),
+                         axis_types=(jax.sharding.AxisType.Auto,) * len(shape))
+
+
 def make_production_mesh(*, multi_pod: bool = False):
-    shape = (2, 16, 16) if multi_pod else (16, 16)
-    axes = ("pod", "data", "model") if multi_pod else ("data", "model")
-    import numpy as _np
-    n = int(_np.prod(shape))
-    devs = jax.devices()
-    if len(devs) == n:
-        return jax.make_mesh(shape, axes)
     # host-device stand-ins may exceed the mesh size (512 forced for the
     # dry-run; the single-pod mesh takes the first 256)
-    assert len(devs) >= n, (len(devs), n)
-    return jax.sharding.Mesh(_np.asarray(devs[:n]).reshape(shape), axes)
+    if multi_pod:
+        return make_mesh((2, 16, 16), ("pod", "data", "model"))
+    return make_mesh((16, 16), ("data", "model"))
 
 
 def make_host_mesh(model: int = 4, data: int = 2):
-    """Small mesh over host CPU devices (tests / examples)."""
-    return jax.make_mesh((data, model), ("data", "model"))
+    """A ``(data, model)`` mesh over the local devices (tests, examples,
+    launchers)."""
+    return make_mesh((data, model), ("data", "model"))
 
 
 def mesh_axes(mesh) -> dict:

@@ -27,7 +27,8 @@ def main():
                     help="static batch size / engine slot count")
     ap.add_argument("--prompt-len", type=int, default=64)
     ap.add_argument("--gen", type=int, default=16)
-    ap.add_argument("--mesh", default="2x4")
+    ap.add_argument("--mesh", default=None,
+                    help="DATAxMODEL (default: 1x<device count>)")
     ap.add_argument("--decode-mode", default="exact",
                     choices=("exact", "prism"))
     ap.add_argument("--backend", default="auto",
@@ -116,9 +117,13 @@ def main():
     from repro.models import transformer as T
     from repro.runtime.serve import (ServeHParams, make_prefill_step,
                                      make_serve_step)
+    from repro.launch.compile_cache import use_compile_cache
+    from repro.launch.mesh import make_mesh
 
-    data, model = (int(x) for x in args.mesh.split("x"))
-    mesh = jax.make_mesh((data, model), ("data", "model"))
+    use_compile_cache()
+    mesh_arg = args.mesh or f"1x{len(jax.devices())}"
+    data, model = (int(x) for x in mesh_arg.split("x"))
+    mesh = make_mesh((data, model), ("data", "model"))
     cfg = get_config(args.arch)
     if args.reduced:
         cfg = cfg.reduced()

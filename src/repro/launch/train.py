@@ -41,9 +41,12 @@ def main():
     from repro.models import transformer as T
     from repro.optim import adamw_init
     from repro.runtime.train import make_train_step, TrainHParams
+    from repro.launch.compile_cache import use_compile_cache
+    from repro.launch.mesh import make_mesh
 
+    use_compile_cache()
     data, model = (int(x) for x in args.mesh.split("x"))
-    mesh = jax.make_mesh((data, model), ("data", "model"))
+    mesh = make_mesh((data, model), ("data", "model"))
     cfg = get_config(args.arch)
     if args.reduced:
         cfg = cfg.reduced()

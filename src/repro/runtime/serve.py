@@ -91,7 +91,6 @@ from jax.sharding import NamedSharding, PartitionSpec as P
 
 from . import paging as _paging
 from .paging import KVCache, PagedLayout, PrefixCache, make_paged_layout
-from ..compat import axis_size, shard_map
 from ..core.attention import (_gqa_logits, _gqa_output, log_repeats,
                               prism_attention)
 from ..core.masks import NEG_INF
@@ -647,7 +646,7 @@ def prism_decode_attention(q, k_loc, v_loc, kz, vz, valid, gz, owner,
 def _seq_index(seq_axes):
     idx = lax.axis_index(seq_axes[0])
     for a in seq_axes[1:]:
-        idx = idx * axis_size(a) + lax.axis_index(a)
+        idx = idx * lax.axis_size(a) + lax.axis_index(a)
     return idx
 
 
@@ -865,7 +864,7 @@ def attn_decode_tp(p, spec: AttnSpec, cfg: ModelConfig, x, c, pos,
     per-layer FSDP gather (the whole weight matrix per token) becomes
     one activation psum.
     """
-    tp = axis_size("model")
+    tp = lax.axis_size("model")
     xn = norm(p["ln1"], x, cfg.norm_kind)
     rp = pos[:, None]                          # (B,1) row positions
     b = x.shape[0]
@@ -929,7 +928,7 @@ class DecodeMoeCtx:
         def undo(y):
             if self.tp:
                 d = lax.axis_index("data")
-                s = y.shape[1] // axis_size("data")
+                s = y.shape[1] // lax.axis_size("data")
                 y = lax.dynamic_slice_in_dim(y, d * s, s, axis=1)
             return lax.all_to_all(y, "model", split_axis=1, concat_axis=0,
                                   tiled=True)
@@ -1199,7 +1198,7 @@ def make_serve_step(cfg: ModelConfig, mesh, params, *,
             return body_core(params_local, cache_local, token, pos,
                              None, None, *deg)
         in_specs = (pspecs, cspecs, vspec, vspec) + extra
-    body_sm = shard_map(
+    body_sm = jax.shard_map(
         body, mesh=mesh,
         in_specs=in_specs,
         out_specs=(lspec, cspecs),
@@ -1450,7 +1449,7 @@ def make_prefill_step(cfg: ModelConfig, mesh, params, prism: PrismConfig,
         if cfg.arch_type == "vlm":
             bspec["embeds"] = P(lay.bspec, None, None)
     lspec = P(lay.bspec, "model" if vocab_sharded else None)
-    body_sm = shard_map(
+    body_sm = jax.shard_map(
         body, mesh=mesh,
         in_specs=(pspecs, bspec),
         out_specs=(lspec, cspecs),
@@ -1710,7 +1709,7 @@ def make_chunk_prefill_step(cfg: ModelConfig, mesh, params, *,
                              nreal, None, None)
         in_specs = (pspecs, cspecs, P(lay.bspec, None), P(lay.bspec),
                     P(lay.bspec))
-    body_sm = shard_map(
+    body_sm = jax.shard_map(
         body, mesh=mesh,
         in_specs=in_specs,
         out_specs=cspecs,
@@ -2094,7 +2093,7 @@ def make_packed_step(cfg: ModelConfig, mesh, params, *,
             return body_core(params_local, cache_local, tokens, slot,
                              pos, off, pre, None, None)
         in_specs = (pspecs, cspecs, vspec, vspec, vspec, vspec, vspec)
-    body_sm = shard_map(
+    body_sm = jax.shard_map(
         body, mesh=mesh,
         in_specs=in_specs,
         out_specs=(lspec, cspecs),

@@ -21,7 +21,6 @@ import numpy as np
 from jax import lax
 from jax.sharding import NamedSharding, PartitionSpec as P
 
-from ..compat import axis_size, shard_map
 from ..core.protocol import PrismConfig
 from ..models import transformer as T
 from ..models.config import ModelConfig
@@ -105,7 +104,7 @@ def vp_lm_loss(x_local, table_local, labels_local, *, softcap=None,
         # `total` is the all-model-shards sum (post-psum, replicated over
         # 'model'); convert to this device's share so downstream psums
         # remain uniform across both vocab modes.
-        total = total / axis_size("model")
+        total = total / lax.axis_size("model")
     return total / global_tokens
 
 
@@ -272,7 +271,7 @@ def make_train_step(cfg: ModelConfig, mesh, params, prism: PrismConfig,
         }
         return grads, metrics
 
-    body_sm = shard_map(
+    body_sm = jax.shard_map(
         body, mesh=mesh,
         in_specs=(pspecs, bspec),
         out_specs=(pspecs, P()),

@@ -27,8 +27,6 @@ import jax
 import jax.numpy as jnp
 from jax import lax
 
-from ..compat import axis_size
-
 from ..core.segment_means import segment_means, segment_sizes, segment_bounds
 from ..core.protocol import PrismConfig
 from ..models.context import SeqContext, AugmentedKV
@@ -57,7 +55,7 @@ class ShardedPrismContext(SeqContext):
     def _index(self):
         idx = lax.axis_index(self.seq_axes[0])
         for a in self.seq_axes[1:]:
-            idx = idx * axis_size(a) + lax.axis_index(a)
+            idx = idx * lax.axis_size(a) + lax.axis_index(a)
         return idx
 
     def _gather(self, x):
@@ -150,7 +148,7 @@ class ShardedPrismContext(SeqContext):
         # a corrective permute on the major axis for the wrap column.
         minor = self.seq_axes[-1]
         major = self.seq_axes[0]
-        pm = axis_size(minor)
+        pm = lax.axis_size(minor)
         # shift-by-h on the flattened index decomposes into minor shift and
         # major carry; for h < pm (always true here) one carry at most.
         h = perm[0][1] - perm[0][0]
@@ -160,7 +158,7 @@ class ShardedPrismContext(SeqContext):
             x, minor, [(pm - h + i, i) for i in range(h)])
         carried = lax.ppermute(
             carried, major,
-            [(s, s + 1) for s in range(axis_size(major) - 1)])
+            [(s, s + 1) for s in range(lax.axis_size(major) - 1)])
         idx_minor = lax.axis_index(minor)
         return jnp.where(idx_minor < h, carried, shifted)
 
@@ -224,7 +222,7 @@ class ShardedPrismContext(SeqContext):
     def expert_exchange(self, buf):
         """(E, cap, D) -> (E_local, P·cap, D) via tiled all_to_all."""
         ax = self.axis
-        p = axis_size(ax)
+        p = lax.axis_size(ax)
         out = lax.all_to_all(buf, ax, split_axis=0, concat_axis=1, tiled=True)
 
         def undo(y):

@@ -41,6 +41,7 @@ from repro.models.config import ModelConfig
 from repro.models import transformer as T
 from repro.runtime.serve import ServeHParams
 from repro.serving import ServingEngine
+from repro.launch.mesh import make_mesh
 
 
 CFG = ModelConfig(
@@ -53,7 +54,7 @@ CFG = ModelConfig(
 def check(mode: str, chunk_len: int, *, ground_truth: bool = False,
           prefill_mode: str = "chunked", token_budget: int = 11,
           paged: bool = True) -> bool:
-    mesh = jax.make_mesh((2, 4), ("data", "model"))
+    mesh = make_mesh((2, 4), ("data", "model"))
     params = T.init(CFG, jax.random.PRNGKey(0))
     hp = ServeHParams(decode_mode=mode, ssm_chunk=8, means_cr=4.0)
     kw = dict(n_slots=4, prefill_len=32, max_cache=48, hp=hp,
@@ -256,7 +257,7 @@ def check_degraded(mode: str) -> bool:
     from repro.runtime.faults import FaultPlan, FaultSpec
     from repro.serving import StreamingEngine
 
-    mesh = jax.make_mesh((2, 4), ("data", "model"))
+    mesh = make_mesh((2, 4), ("data", "model"))
     params = T.init(CFG, jax.random.PRNGKey(0))
     hp = ServeHParams(decode_mode=mode, ssm_chunk=8, means_cr=4.0)
     kw = dict(n_slots=4, prefill_len=32, max_cache=48, hp=hp,

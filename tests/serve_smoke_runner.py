@@ -17,10 +17,11 @@ from repro.models import transformer as T
 from repro.models.config import ModelConfig
 from repro.runtime.serve import (ServeHParams, make_prefill_step,
                                  make_serve_step, make_layout, grow_cache)
+from repro.launch.mesh import make_mesh
 
 
 def check(name, cfg, mode, *, atol, batch=8, n=32, gen=4, backend="auto"):
-    mesh = jax.make_mesh((2, 4), ("data", "model"))
+    mesh = make_mesh((2, 4), ("data", "model"))
     key = jax.random.PRNGKey(0)
     params = T.init(cfg, key)
     total = n + gen

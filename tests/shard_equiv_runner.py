@@ -25,6 +25,7 @@ from repro.models.context import SimulatedContext
 from repro.optim import adamw_init
 from repro.runtime.train import make_train_step, TrainHParams
 from repro.runtime.losses import softmax_xent
+from repro.launch.mesh import make_mesh
 
 
 def ref_loss(cfg, params, tokens, labels, prism):
@@ -34,7 +35,7 @@ def ref_loss(cfg, params, tokens, labels, prism):
 
 
 def check(name, cfg, prism, *, atol=2e-4, compare_grads=True):
-    mesh = jax.make_mesh((2, 4), ("data", "model"))
+    mesh = make_mesh((2, 4), ("data", "model"))
     key = jax.random.PRNGKey(0)
     params = T.init(cfg, key)
     B, N = 8, 32

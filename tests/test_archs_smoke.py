@@ -13,6 +13,7 @@ from repro.core.protocol import PrismConfig
 from repro.models import transformer as T
 from repro.optim import adamw_init
 from repro.runtime.train import make_train_step, TrainHParams
+from repro.launch.mesh import make_mesh
 
 B, N = 2, 32
 
@@ -49,7 +50,7 @@ def test_reduced_forward(arch):
 @pytest.mark.parametrize("arch", ASSIGNED_ARCHS)
 def test_reduced_train_step(arch):
     cfg = get_config(arch).reduced()
-    mesh = jax.make_mesh((1, 1), ("data", "model"))
+    mesh = make_mesh((1, 1), ("data", "model"))
     params = T.init(cfg, jax.random.PRNGKey(0))
     prism = PrismConfig(P=1, cr=4.0, mode="prism")
     hp = TrainHParams(lr=1e-3, warmup=1, loss_chunks=4, ssm_chunk=8)
@@ -82,7 +83,7 @@ def test_reduced_decode_step(arch):
     from repro.runtime.serve import (ServeHParams, grow_cache,
                                      make_prefill_step, make_serve_step)
     cfg = get_config(arch).reduced()
-    mesh = jax.make_mesh((1, 1), ("data", "model"))
+    mesh = make_mesh((1, 1), ("data", "model"))
     params = T.init(cfg, jax.random.PRNGKey(0))
     n, gen = 16, 2
     hp = ServeHParams(decode_mode="exact", ssm_chunk=8)

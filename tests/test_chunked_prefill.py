@@ -22,6 +22,7 @@ from repro.runtime.serve import (ServeHParams, grow_cache, init_cache,
                                  make_chunk_prefill_step,
                                  make_prefill_step, make_serve_step)
 from repro.serving import ServingEngine
+from repro.launch.mesh import make_mesh
 
 
 TINY = ModelConfig(
@@ -32,7 +33,7 @@ TINY = ModelConfig(
 
 
 def _mesh():
-    return jax.make_mesh((1, 1), ("data", "model"))
+    return make_mesh((1, 1), ("data", "model"))
 
 
 def _chunk_fill(chunk, params, cache, prompts, chunk_len, n_slots):

@@ -179,16 +179,14 @@ def test_dispatch_rules():
     assert use_pallas("pallas") and not use_pallas("jnp")
     with pytest.raises(ValueError):
         resolve_backend("cuda")
-    # env override applies to 'auto'/None but never beats an explicit pick
+    # the environment never moves 'auto' off the platform's choice (an
+    # inherited variable must not put a chip run on the jnp oracle)
     import os
-    os.environ["PRISM_KERNEL_BACKEND"] = "pallas"
+    other = "jnp" if auto == "pallas" else "pallas"
+    os.environ["PRISM_KERNEL_BACKEND"] = other
     try:
-        assert resolve_backend("auto") == "pallas"
-        assert resolve_backend(None) == "pallas"
-        assert resolve_backend("jnp") == "jnp"
-        os.environ["PRISM_KERNEL_BACKEND"] = "bogus"
-        with pytest.raises(ValueError):
-            resolve_backend("auto")
+        assert resolve_backend("auto") == auto
+        assert resolve_backend(None) == auto
     finally:
         del os.environ["PRISM_KERNEL_BACKEND"]
     # interpret auto-detection: emulate everywhere but real TPU
@@ -234,12 +232,13 @@ def test_serve_step_backend_equivalence():
     from repro.models.config import ModelConfig
     from repro.runtime.serve import (ServeHParams, grow_cache,
                                      make_prefill_step, make_serve_step)
+    from repro.launch.mesh import make_mesh
     tiny = ModelConfig(
         name="tiny-kb", arch_type="dense", n_layers=2, d_model=32,
         n_heads=4, n_kv_heads=2, head_dim=8, d_ff=64, vocab_size=61,
         mlp_kind="swiglu", norm_kind="rmsnorm", pos="rope",
         tie_embeddings=True)
-    mesh = jax.make_mesh((1, 1), ("data", "model"))
+    mesh = make_mesh((1, 1), ("data", "model"))
     params = T.init(tiny, jax.random.PRNGKey(0))
     n0, cap = 8, 12
     prompt = jax.random.randint(jax.random.PRNGKey(1), (2, n0), 1,

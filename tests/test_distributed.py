@@ -59,11 +59,12 @@ from repro.models.config import ModelConfig
 from repro.optim import adamw_init
 from repro.runtime.train import make_train_step, TrainHParams
 from repro.launch.roofline import collective_bytes
+from repro.launch.mesh import make_mesh
 
 cfg = ModelConfig(name="t", arch_type="dense", n_layers=2, d_model=64,
                   n_heads=4, n_kv_heads=2, head_dim=16, d_ff=128,
                   vocab_size=64, pos="rope")
-mesh = jax.make_mesh((2, 4), ("data", "model"))
+mesh = make_mesh((2, 4), ("data", "model"))
 key = jax.random.PRNGKey(0)
 params = T.init(cfg, key)
 hp = TrainHParams(remat=False, loss_chunks=2)

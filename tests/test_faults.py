@@ -29,6 +29,7 @@ from repro.runtime.faults import (KINDS, FaultInjector, FaultPlan,
                                   FaultSpec)
 from repro.runtime.offload import KVStore
 from repro.serving import SamplingParams, ServingEngine
+from repro.launch.mesh import make_mesh
 
 
 TINY = ModelConfig(
@@ -39,7 +40,7 @@ TINY = ModelConfig(
 
 
 def _mesh():
-    return jax.make_mesh((1, 1), ("data", "model"))
+    return make_mesh((1, 1), ("data", "model"))
 
 
 def _engine(params, mesh, **kw):

@@ -30,6 +30,7 @@ from repro.runtime.serve import (ServeHParams, init_cache,
                                  make_packed_step, make_prefill_step,
                                  trace_counts)
 from repro.serving import ServingEngine
+from repro.launch.mesh import make_mesh
 
 
 TINY = ModelConfig(
@@ -40,7 +41,7 @@ TINY = ModelConfig(
 
 
 def _mesh():
-    return jax.make_mesh((1, 1), ("data", "model"))
+    return make_mesh((1, 1), ("data", "model"))
 
 
 def _oracle(params, prompt, n_gen):

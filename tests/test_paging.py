@@ -17,6 +17,7 @@ from repro.runtime.paging import (NO_PAGE, KVCache, PagedLayout, PageTable,
 from repro.runtime.serve import (ServeHParams, make_kv_cache, make_layout,
                                  seq_shards)
 from repro.serving import EngineConfig, ServingEngine
+from repro.launch.mesh import make_mesh
 
 
 TINY = ModelConfig(
@@ -27,7 +28,7 @@ TINY = ModelConfig(
 
 
 def _mesh():
-    return jax.make_mesh((1, 1), ("data", "model"))
+    return make_mesh((1, 1), ("data", "model"))
 
 
 # --------------------------------------------------------------------------

@@ -30,6 +30,7 @@ from repro.runtime.paging import KVCache, PagedLayout, PrefixCache
 from repro.runtime.serve import ServeHParams
 from repro.serving import (EngineConfig, FifoScheduler, Request,
                            RequestState, SamplingParams, ServingEngine)
+from repro.launch.mesh import make_mesh
 
 
 TINY = ModelConfig(
@@ -40,7 +41,7 @@ TINY = ModelConfig(
 
 
 def _mesh():
-    return jax.make_mesh((1, 1), ("data", "model"))
+    return make_mesh((1, 1), ("data", "model"))
 
 
 def _req(rid, plen=6, max_new=4, priority=0, arrival=0.0,

@@ -96,8 +96,9 @@ def test_sharding_rules_paths():
     from repro.sharding.rules import param_specs, spec_tree
     from repro.configs import get_config
     from repro.launch.inputs import param_shapes
+    from repro.launch.mesh import make_mesh
     cfg = get_config("olmoe-1b-7b")
-    mesh = jax.make_mesh((1, 1), ("data", "model"))
+    mesh = make_mesh((1, 1), ("data", "model"))
 
     class FakeAx(dict):
         pass

@@ -14,6 +14,7 @@ from repro.runtime.serve import (ServeHParams, grow_cache, init_cache,
                                  make_serve_step, reset_cache_row)
 from repro.serving import (FifoScheduler, Request, SamplingParams,
                            ServingEngine, sample_token)
+from repro.launch.mesh import make_mesh
 
 
 TINY = ModelConfig(
@@ -24,7 +25,7 @@ TINY = ModelConfig(
 
 
 def _mesh():
-    return jax.make_mesh((1, 1), ("data", "model"))
+    return make_mesh((1, 1), ("data", "model"))
 
 
 def _req(rid, prompt=(1, 2, 3), **kw):

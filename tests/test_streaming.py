@@ -20,6 +20,7 @@ import jax
 from repro.models import transformer as T
 from repro.models.config import ModelConfig
 from repro.serving import ServingEngine, StreamingEngine, serve_stream
+from repro.launch.mesh import make_mesh
 
 TINY = ModelConfig(
     name="tiny-stream", arch_type="dense", n_layers=2, d_model=32,
@@ -35,7 +36,7 @@ def _setup():
     (computed once — every test compares against the same oracle)."""
     if _CACHE:
         return _CACHE
-    mesh = jax.make_mesh((1, 1), ("data", "model"))
+    mesh = make_mesh((1, 1), ("data", "model"))
     params = T.init(TINY, jax.random.PRNGKey(0))
     rng = np.random.default_rng(0)
     prompts = [rng.integers(1, TINY.vocab_size,
