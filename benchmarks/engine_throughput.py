@@ -557,7 +557,7 @@ def run_stream_wall(trace, *, overlap: bool) -> dict:
     w0 = time.perf_counter()
     seng.run_sync()
     wall_s = time.perf_counter() - w0
-    itl = [dt for ds in seng.itl_samples().values() for dt in ds]
+    itl = seng.itl_samples()
     s = eng.stats.summary()
     pct = lambda xs, q: float(np.percentile(xs, q)) if xs else 0.0
     return {

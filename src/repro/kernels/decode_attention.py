@@ -273,6 +273,7 @@ def flash_decode_stats(
             pltpu.VMEM((grp, hd), jnp.float32),      # output accumulator
         ],
         interpret=interpret,
+        name="flash_decode_stats",
     )(*args)
 
     m_p = m_o.reshape(b, hq)[:, :, None, None]

@@ -140,4 +140,5 @@ def prism_flash_attention(
             pltpu.VMEM((block_q, hd), jnp.float32),  # output accumulator
         ],
         interpret=interpret,
+        name="prism_flash_attention",
     )(row_pos, col_lo, col_hi, log_g, q, k, v)

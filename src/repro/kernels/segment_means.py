@@ -65,5 +65,6 @@ def segment_means_op(x, *, L: int, block_d: int = 512,
                                lambda i, l, j: (i, l, 0, j)),
         out_shape=jax.ShapeDtypeStruct((b, L, 1, d), x.dtype),
         interpret=interpret,
+        name="segment_means",
     )(x.reshape(b, L, seg, d))
     return out[:, :, 0]

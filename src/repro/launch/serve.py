@@ -233,7 +233,7 @@ def main():
                 fins[fin] = fins.get(fin, 0) + 1
             print(f"[stream] {len(got)} streams closed: "
                   + ", ".join(f"{k}={v}" for k, v in sorted(fins.items())))
-            itl = [dt for ds in seng.itl_samples().values() for dt in ds]
+            itl = seng.itl_samples()
             if itl:
                 print(f"[stream] itl_p50_s {float(np.percentile(itl, 50)):.4f}"
                       f"  itl_p99_s {float(np.percentile(itl, 99)):.4f}")

@@ -106,7 +106,8 @@ from ..kernels.segment_means import segment_means_op
 from ..models import transformer as T
 from ..models.config import ModelConfig
 from ..models.layers import (AttnSpec, attn_project_q, attn_project_kv,
-                             attn_output, dense, norm, mlp)
+                             attn_output, dense, norm)
+from ..models.layers import mlp as _mlp
 from ..models.moe import moe_apply
 from ..models.ssm import (mlstm_decode, slstm_decode, mamba2_decode,
                           mlstm_apply, slstm_apply, mamba2_apply)
@@ -122,6 +123,12 @@ from .train import embed_vp, output_table
 #: packed / decode / chunk programs — the regression test in
 #: ``tests/test_packed_step.py`` asserts exactly that.
 trace_counts: collections.Counter = collections.Counter()
+
+#: The feed-forward under the ``mlp`` scope.  Each ``jax.named_scope``
+#: below (``kv_gather``, ``kv_write``, ``attn_kernel``, ``mlp``,
+#: ``lm_head``) rides in the metadata of the operations it covers, so a
+#: device trace can attribute them; it changes no computation.
+mlp = jax.named_scope("mlp")(_mlp)
 
 
 @dataclass(frozen=True)
@@ -414,6 +421,7 @@ def make_kv_store(capacity_bytes: int | None = None):
 # decode attention
 # --------------------------------------------------------------------------
 
+@jax.named_scope("kv_write")
 def _write_slot(cache_kv, new_row, slot, owner):
     """Write (B,1,Hkv,hd) rows into per-request cache slots.
 
@@ -432,6 +440,7 @@ def _write_slot(cache_kv, new_row, slot, owner):
     return cache_kv.at[rows, cols].set(upd)
 
 
+@jax.named_scope("kv_write")
 def _write_chunk(cache_kv, new_rows, slot, owner):
     """Scatter a prefill chunk's (B,C,Hkv,hd) rows into per-request
     cache slots at runtime offsets.  ``slot``/``owner`` are (B,C) —
@@ -447,6 +456,7 @@ def _write_chunk(cache_kv, new_rows, slot, owner):
         new_rows.astype(cache_kv.dtype), mode="drop")
 
 
+@jax.named_scope("kv_write")
 def _write_packed(cache_kv, new_rows, row, col, ok):
     """Scatter a packed tick's (T,Hkv,hd) K/V rows into the
     (B,cap_l,Hkv,hd) cache at per-token (batch row, column) addresses.
@@ -462,6 +472,7 @@ def _write_packed(cache_kv, new_rows, row, col, ok):
                                  mode="drop")
 
 
+@jax.named_scope("kv_gather")
 def _gather_pages(pool, pages):
     """Reassemble virtual cache rows from the page pool: ``pool``
     (n_pages, page_cols, ...) LOCAL shard, ``pages`` (R, ppr) physical
@@ -478,6 +489,7 @@ def _gather_pages(pool, pages):
                      *pool.shape[2:])
 
 
+@jax.named_scope("kv_write")
 def _write_pool(pool, rows, page, poff, ok):
     """Scatter per-item (Hkv, hd) rows into the page pool at
     (physical page, in-page offset) addresses.  Items with ``ok``
@@ -491,6 +503,7 @@ def _write_pool(pool, rows, page, poff, ok):
     return pool.at[pg, po].set(rows.astype(pool.dtype), mode="drop")
 
 
+@jax.named_scope("attn_kernel")
 def decode_attention(q, k, v, valid, axes, scale, *, gz=None, kz=None,
                      vz=None, owner=None, mode="exact", backend="auto"):
     """Single entry point for per-token decode attention — every decode
@@ -531,6 +544,7 @@ def decode_attention(q, k, v, valid, axes, scale, *, gz=None, kz=None,
     return _combine_exact(m_p, l_p, acc_p, axes).astype(v.dtype)
 
 
+@jax.named_scope("attn_kernel")
 def chunk_attention(q, k, v, valid, bias_self, k_new, v_new, axes, scale,
                     backend="auto"):
     """Exact attention for one prefill chunk — the multi-query sibling
@@ -1129,8 +1143,8 @@ def make_serve_step(cfg: ModelConfig, mesh, params, *,
                                           - n_units * u)]}
                  if with_rep else None)
 
-    def body_core(params_local, cache_local, token, pos, page_map,
-                  state_map, lost=None, rep=None):
+    def decode_step(params_local, cache_local, token, pos, page_map,
+                    state_map, lost=None, rep=None):
         trace_counts["serve_step_degraded" if degraded
                      else "serve_step"] += 1
         x = embed_token(cfg, params_local, rules, token, pos,
@@ -1176,11 +1190,14 @@ def make_serve_step(cfg: ModelConfig, mesh, params, *,
                                  degraded=deg)
             new_tail.append(nc)
 
-        x = norm(params_local["final_norm"], x, cfg.norm_kind)
-        table = output_table(params_local, cfg)
-        logits = (x[:, 0] @ table.T.astype(x.dtype)).astype(jnp.float32)
-        if cfg.logit_softcap:
-            logits = cfg.logit_softcap * jnp.tanh(logits / cfg.logit_softcap)
+        with jax.named_scope("lm_head"):
+            x = norm(params_local["final_norm"], x, cfg.norm_kind)
+            table = output_table(params_local, cfg)
+            logits = (x[:, 0] @ table.T.astype(x.dtype)).astype(
+                jnp.float32)
+            if cfg.logit_softcap:
+                logits = cfg.logit_softcap * jnp.tanh(
+                    logits / cfg.logit_softcap)
         return logits, {"scan": list(new_stacks), "tail": new_tail}
 
     vspec = P(None) if paging is not None else P(lay.bspec)
@@ -1189,15 +1206,16 @@ def make_serve_step(cfg: ModelConfig, mesh, params, *,
     extra = ()
     if degraded:
         extra = ((P(None), rep_specs) if with_rep else (P(None),))
+    # the program is named after the function shard_map wraps:
+    # ``jit_decode_step`` in HLO and in a device trace
     if paging is not None:
-        body = body_core
+        body = decode_step
         in_specs = (pspecs, cspecs, vspec, vspec, P(None), P(None)) \
             + extra
-    else:
-        def body(params_local, cache_local, token, pos, *deg):
-            return body_core(params_local, cache_local, token, pos,
-                             None, None, *deg)
-        in_specs = (pspecs, cspecs, vspec, vspec) + extra
+    else:                              # degraded needs paging: no extra
+        body = functools.partial(decode_step, page_map=None,
+                                 state_map=None)
+        in_specs = (pspecs, cspecs, vspec, vspec)
     body_sm = jax.shard_map(
         body, mesh=mesh,
         in_specs=in_specs,
@@ -1218,6 +1236,7 @@ def make_serve_step(cfg: ModelConfig, mesh, params, *,
 # prefill
 # --------------------------------------------------------------------------
 
+@jax.named_scope("attn_kernel")
 def _prefill_attention(q, k, v, akv, spec: AttnSpec, cfg: ModelConfig,
                        hp: ServeHParams):
     """Route the prefill attention through the Pallas flash kernel when
@@ -1332,7 +1351,7 @@ def make_prefill_step(cfg: ModelConfig, mesh, params, prism: PrismConfig,
                             else prism.L)
     prism_augment = prism_cfg.mode == "prism"
 
-    def body(params_local, batch_local):
+    def prefill_step(params_local, batch_local):
         trace_counts["prefill_step"] += 1
         ctx = ShardedPrismContext(
             prism_cfg, axis=lay.seq_axes[-1], n_shards=lay.n_seq,
@@ -1433,12 +1452,15 @@ def make_prefill_step(cfg: ModelConfig, mesh, params, prism: PrismConfig,
             x, c = one_block(kind, p, shared, x)
             tail_caches.append(c)
 
-        x = norm(params_local["final_norm"], x, cfg.norm_kind)
-        last = ctx.last_shard(x[:, -1])                    # (B, D)
-        table = output_table(params_local, cfg)
-        logits = (last @ table.T.astype(last.dtype)).astype(jnp.float32)
-        if cfg.logit_softcap:
-            logits = cfg.logit_softcap * jnp.tanh(logits / cfg.logit_softcap)
+        with jax.named_scope("lm_head"):
+            x = norm(params_local["final_norm"], x, cfg.norm_kind)
+            last = ctx.last_shard(x[:, -1])                # (B, D)
+            table = output_table(params_local, cfg)
+            logits = (last @ table.T.astype(last.dtype)).astype(
+                jnp.float32)
+            if cfg.logit_softcap:
+                logits = cfg.logit_softcap * jnp.tanh(
+                    logits / cfg.logit_softcap)
         return logits, {"scan": list(cache_stacks), "tail": tail_caches}
 
     bspec = {}
@@ -1450,7 +1472,7 @@ def make_prefill_step(cfg: ModelConfig, mesh, params, prism: PrismConfig,
             bspec["embeds"] = P(lay.bspec, None, None)
     lspec = P(lay.bspec, "model" if vocab_sharded else None)
     body_sm = jax.shard_map(
-        body, mesh=mesh,
+        prefill_step, mesh=mesh,
         in_specs=(pspecs, bspec),
         out_specs=(lspec, cspecs),
         check_vma=False)
@@ -1658,8 +1680,8 @@ def make_chunk_prefill_step(cfg: ModelConfig, mesh, params, *,
                 f"chunked prefill needs position-addressed attention "
                 f"caches; arch {cfg.name!r} has block kind {kind!r}")
 
-    def body_core(params_local, cache_local, tokens, off, nreal,
-                  page_map, state_map):
+    def chunk_prefill_step(params_local, cache_local, tokens, off, nreal,
+                           page_map, state_map):
         trace_counts["chunk_prefill_step"] += 1
         j = jnp.arange(chunk_len)
         alive = (off[:, None] >= 0) & (j[None, :] < nreal[:, None])
@@ -1700,13 +1722,12 @@ def make_chunk_prefill_step(cfg: ModelConfig, mesh, params, *,
         return {"scan": list(new_stacks), "tail": new_tail}
 
     if paging is not None:
-        body = body_core
+        body = chunk_prefill_step
         in_specs = (pspecs, cspecs, P(None), P(None), P(None),
                     P(None), P(None))
     else:
-        def body(params_local, cache_local, tokens, off, nreal):
-            return body_core(params_local, cache_local, tokens, off,
-                             nreal, None, None)
+        body = functools.partial(chunk_prefill_step, page_map=None,
+                                 state_map=None)
         in_specs = (pspecs, cspecs, P(lay.bspec, None), P(lay.bspec),
                     P(lay.bspec))
     body_sm = jax.shard_map(
@@ -1729,6 +1750,7 @@ def make_chunk_prefill_step(cfg: ModelConfig, mesh, params, *,
 # token-packed unified serving step (mixed prefill + decode per tick)
 # --------------------------------------------------------------------------
 
+@jax.named_scope("attn_kernel")
 def packed_attention(q, k, v, valid, bias_self, k_new, v_new, axes, scale,
                      backend="auto"):
     """Exact attention for one token-packed tick — the ragged
@@ -1823,18 +1845,20 @@ def attn_packed(p, spec: AttnSpec, cfg: ModelConfig, x, c, meta,
         new_c = dict(c, k=k_pool, v=v_pool)
         # one gather per SLOT, then a per-token row take — same shape
         # the dense path produces from its row cache
-        k_t = jnp.take(_gather_pages(k_pool, page_map), row, axis=0)
-        v_t = jnp.take(_gather_pages(v_pool, page_map), row, axis=0)
-        mapped = jnp.take(jnp.repeat(page_map >= 0, pc, axis=1),
-                          row, axis=0)                     # (T, cap_l)
+        with jax.named_scope("kv_gather"):
+            k_t = jnp.take(_gather_pages(k_pool, page_map), row, axis=0)
+            v_t = jnp.take(_gather_pages(v_pool, page_map), row, axis=0)
+            mapped = jnp.take(jnp.repeat(page_map >= 0, pc, axis=1),
+                              row, axis=0)                 # (T, cap_l)
     else:
         k_c = _write_packed(c["k"], k_new[:, 0], row_loc, col, wr)
         v_c = _write_packed(c["v"], v_new[:, 0], row_loc, col, wr)
         new_c = dict(c, k=k_c, v=v_c)
         b_loc = k_c.shape[0]
         row = jnp.clip(row_loc, 0, b_loc - 1)
-        k_t = jnp.take(k_c, row, axis=0)       # (T, cap_l, Hkv, hd)
-        v_t = jnp.take(v_c, row, axis=0)
+        with jax.named_scope("kv_gather"):
+            k_t = jnp.take(k_c, row, axis=0)   # (T, cap_l, Hkv, hd)
+            v_t = jnp.take(v_c, row, axis=0)
         mapped = True
 
     # prior columns: strictly before the request's tick-start offset,
@@ -2026,8 +2050,8 @@ def make_packed_step(cfg: ModelConfig, mesh, params, *,
     b_loc = batch // n_b
     head_rows = min(batch, token_budget)   # decode tokens pack first
 
-    def body_core(params_local, cache_local, tokens, slot, pos, off,
-                  pre, page_map, state_map):
+    def packed_step(params_local, cache_local, tokens, slot, pos, off,
+                    pre, page_map, state_map):
         trace_counts["packed_step"] += 1
         if paging is not None:
             # pool replicated over the batch axes: every replica packs
@@ -2069,29 +2093,30 @@ def make_packed_step(cfg: ModelConfig, mesh, params, *,
                                  page_map, state_map)
             new_tail.append(nc)
 
-        x = norm(params_local["final_norm"], x, cfg.norm_kind)
-        table = output_table(params_local, cfg)
         # only decode tokens are ever sampled, and plan_tick packs them
         # first — at most n_slots of them — so the LM head runs over
         # the static decode prefix, not the whole budget (a prefill-
         # heavy tick would otherwise pay budget/n_slots times the
         # needed head FLOPs on logits nobody reads)
-        xh = x[:head_rows, 0]
-        logits = (xh @ table.T.astype(xh.dtype)).astype(jnp.float32)
-        if cfg.logit_softcap:
-            logits = cfg.logit_softcap * jnp.tanh(logits / cfg.logit_softcap)
+        with jax.named_scope("lm_head"):
+            x = norm(params_local["final_norm"], x, cfg.norm_kind)
+            table = output_table(params_local, cfg)
+            xh = x[:head_rows, 0]
+            logits = (xh @ table.T.astype(xh.dtype)).astype(jnp.float32)
+            if cfg.logit_softcap:
+                logits = cfg.logit_softcap * jnp.tanh(
+                    logits / cfg.logit_softcap)
         return logits, {"scan": list(new_stacks), "tail": new_tail}
 
     vspec = P(None)                    # packed vectors ride replicated
     lspec = P(None, "model" if vocab_sharded else None)
     if paging is not None:
-        body = body_core
+        body = packed_step
         in_specs = (pspecs, cspecs, vspec, vspec, vspec, vspec, vspec,
                     P(None), P(None))
     else:
-        def body(params_local, cache_local, tokens, slot, pos, off, pre):
-            return body_core(params_local, cache_local, tokens, slot,
-                             pos, off, pre, None, None)
+        body = functools.partial(packed_step, page_map=None,
+                                 state_map=None)
         in_specs = (pspecs, cspecs, vspec, vspec, vspec, vspec, vspec)
     body_sm = jax.shard_map(
         body, mesh=mesh,

@@ -77,6 +77,7 @@ from ..runtime.serve import (ServeHParams, _paged_placement, make_layout,
                              make_serve_step, seq_shards)
 from .sampling import SamplingParams, sample_token
 from .scheduler import EngineStats, FifoScheduler, Request, RequestState
+from .spans import span
 
 
 @dataclass(frozen=True)
@@ -514,7 +515,8 @@ class ServingEngine:
 
     def _step_inner(self) -> str:
         sch = self._sched
-        self._release_arrivals()
+        with span(self.stats, "serve.admit"):
+            self._release_arrivals()
         if self.stats.t_start is None:
             self.stats.t_start = self.now()
         if self._has_deadlines:
@@ -535,43 +537,47 @@ class ServingEngine:
         if self.prefill_mode == "padded":
             if sch.want_prefill():
                 return self._padded_flush()
-        elif self.prefill_mode == "chunked":
-            self._admit_or_preempt()       # host-side: slots + pages
-            if sch.want_chunk():
-                return self._chunk_step()
-        else:                              # packed: one program per tick
-            self._admit_or_preempt()       # host-side: slots + pages
-            if any(st.prefilling for st in sch.active.values()):
-                return self._packed_tick()
+        else:                              # packed or chunked
+            with span(self.stats, "serve.admit"):
+                self._admit_or_preempt()   # host-side: slots + pages
+            if self.prefill_mode == "chunked":
+                if sch.want_chunk():
+                    return self._chunk_step()
+            elif any(st.prefilling for st in sch.active.values()):
+                return self._packed_tick()  # packed: one program a tick
 
         decoding = sch.decoding()
         if decoding:
-            self._maybe_poison()
-            tok = np.zeros(self.n_slots, np.int32)
-            pos = np.full(self.n_slots, -1, np.int32)
-            for st in decoding:
-                tok[st.slot] = st.next_token
-                pos[st.slot] = st.pos
+            with span(self.stats, "serve.plan"):
+                self._maybe_poison()
+                tok = np.zeros(self.n_slots, np.int32)
+                pos = np.full(self.n_slots, -1, np.int32)
+                for st in decoding:
+                    tok[st.slot] = st.next_token
+                    pos[st.slot] = st.pos
             t0 = self.now()
-            logits, self._kv.storage = self._step(
-                self.params, self._kv.storage, jnp.asarray(tok),
-                jnp.asarray(pos), *self._maps())
-            rows = np.asarray(jax.device_get(logits))
-            now = self.now()
-            self.stats.step_latency.append(now - t0)
-            self.stats.occupancy.append(len(sch.active) / self.n_slots)
-            self.stats.decode_steps += 1
-            # ONE fused non-finite reduction over the tick's logits —
-            # the quarantine trigger costs a single host-side pass
-            bad = (~np.isfinite(rows).all(axis=-1)
-                   if self._nan_guard else None)
-            for st in decoding:
-                if bad is not None and bad[st.slot]:
-                    self._quarantine(st)
-                else:
-                    self._advance_decode(st, rows[st.slot], now)
-            sch.note_decode()
-            self.stats.t_end = self.now()
+            with span(self.stats, "serve.launch"):
+                logits, self._kv.storage = self._step(
+                    self.params, self._kv.storage, jnp.asarray(tok),
+                    jnp.asarray(pos), *self._maps())
+            with span(self.stats, "serve.wait"):
+                rows = np.asarray(jax.device_get(logits))
+            with span(self.stats, "serve.reconcile"):
+                now = self.now()
+                self.stats.step_latency.append(now - t0)
+                self.stats.occupancy.append(len(sch.active) / self.n_slots)
+                self.stats.decode_steps += 1
+                # ONE fused non-finite reduction over the tick's logits
+                # — the quarantine trigger costs a single host-side pass
+                bad = (~np.isfinite(rows).all(axis=-1)
+                       if self._nan_guard else None)
+                for st in decoding:
+                    if bad is not None and bad[st.slot]:
+                        self._quarantine(st)
+                    else:
+                        self._advance_decode(st, rows[st.slot], now)
+                sch.note_decode()
+                self.stats.t_end = self.now()
             return "decode"
         return "idle"
 
@@ -730,8 +736,9 @@ class ServingEngine:
         row) into the host store, free the device footprint, and either
         park it for automatic resume or hand it to the caller
         (suspend)."""
-        n = self._kv.spill(st.req.rid, st.slot, self._store,
-                           tokens=st.nprefilled)
+        with span(self.stats, "serve.spill"):
+            n = self._kv.spill(st.req.rid, st.slot, self._store,
+                               tokens=st.nprefilled)
         self.stats.preemptions += 1
         self.stats.spilled_pages += n
         self._drop_replica(st.slot)
@@ -1236,58 +1243,64 @@ class ServingEngine:
         offset (the rewind then re-feeds the last prompt token, exactly
         as in chunked mode, so output stays token-identical)."""
         sch = self._sched
-        decode, prefill = sch.plan_tick(self.token_budget)
-        tb = self.token_budget
-        tok = np.zeros(tb, np.int32)
-        slot = np.full(tb, -1, np.int32)
-        pos = np.full(tb, -1, np.int32)
-        off = np.full(tb, -1, np.int32)
-        pre = np.zeros(tb, np.int32)
-        i = 0
-        dec_rows = []
-        for st in decode:
-            tok[i], slot[i] = st.next_token, st.slot
-            pos[i] = off[i] = st.pos
-            dec_rows.append((i, st))
-            i += 1
-        n_prefill = 0
-        for st, take in prefill:
-            o = st.nprefilled
-            tok[i:i + take] = st.req.prompt[o:o + take]
-            slot[i:i + take] = st.slot
-            pos[i:i + take] = np.arange(o, o + take)
-            off[i:i + take] = o
-            pre[i:i + take] = 1
-            i += take
-            n_prefill += take
+        with span(self.stats, "serve.plan"):
+            decode, prefill = sch.plan_tick(self.token_budget)
+            tb = self.token_budget
+            tok = np.zeros(tb, np.int32)
+            slot = np.full(tb, -1, np.int32)
+            pos = np.full(tb, -1, np.int32)
+            off = np.full(tb, -1, np.int32)
+            pre = np.zeros(tb, np.int32)
+            i = 0
+            dec_rows = []
+            for st in decode:
+                tok[i], slot[i] = st.next_token, st.slot
+                pos[i] = off[i] = st.pos
+                dec_rows.append((i, st))
+                i += 1
+            n_prefill = 0
+            for st, take in prefill:
+                o = st.nprefilled
+                tok[i:i + take] = st.req.prompt[o:o + take]
+                slot[i:i + take] = st.slot
+                pos[i:i + take] = np.arange(o, o + take)
+                off[i:i + take] = o
+                pre[i:i + take] = 1
+                i += take
+                n_prefill += take
 
         t0 = self.now()
-        logits, self._kv.storage = self._packed(
-            self.params, self._kv.storage, jnp.asarray(tok),
-            jnp.asarray(slot), jnp.asarray(pos), jnp.asarray(off),
-            jnp.asarray(pre), *self._maps())
-        rows = np.asarray(jax.device_get(logits))
-        now = self.now()
-        self.stats.step_latency.append(now - t0)
-        self.stats.occupancy.append(len(sch.active) / self.n_slots)
-        self.stats.packed_ticks += 1
-        self.stats.packed_decode_tokens += len(dec_rows)
-        self.stats.packed_prefill_tokens += n_prefill
-        self.stats.prefill_tokens += n_prefill
-        # ONE fused non-finite reduction over the tick's logits; only
-        # decode rows sample, so only they can quarantine
-        bad = (~np.isfinite(rows).all(axis=-1)
-               if self._nan_guard else None)
-        for j, st in dec_rows:
-            if bad is not None and bad[j]:
-                self._quarantine(st)
-            else:
-                self._advance_decode(st, rows[j], now)
-        for st, take in prefill:
-            st.nprefilled += take
-            if not st.prefilling:
-                st.begin_decode()          # rewind: re-feed last token
-        self.stats.t_end = self.now()
+        for st, _ in prefill:
+            st.note_dispatch(t0)
+        with span(self.stats, "serve.launch"):
+            logits, self._kv.storage = self._packed(
+                self.params, self._kv.storage, jnp.asarray(tok),
+                jnp.asarray(slot), jnp.asarray(pos), jnp.asarray(off),
+                jnp.asarray(pre), *self._maps())
+        with span(self.stats, "serve.wait"):
+            rows = np.asarray(jax.device_get(logits))
+        with span(self.stats, "serve.reconcile"):
+            now = self.now()
+            self.stats.step_latency.append(now - t0)
+            self.stats.occupancy.append(len(sch.active) / self.n_slots)
+            self.stats.packed_ticks += 1
+            self.stats.packed_decode_tokens += len(dec_rows)
+            self.stats.packed_prefill_tokens += n_prefill
+            self.stats.prefill_tokens += n_prefill
+            # ONE fused non-finite reduction over the tick's logits; only
+            # decode rows sample, so only they can quarantine
+            bad = (~np.isfinite(rows).all(axis=-1)
+                   if self._nan_guard else None)
+            for j, st in dec_rows:
+                if bad is not None and bad[j]:
+                    self._quarantine(st)
+                else:
+                    self._advance_decode(st, rows[j], now)
+            for st, take in prefill:
+                st.nprefilled += take
+                if not st.prefilling:
+                    st.begin_decode()      # rewind: re-feed last token
+            self.stats.t_end = self.now()
         return "packed"
 
     def _chunk_step(self) -> str:
@@ -1314,6 +1327,9 @@ class ServingEngine:
                 st.nprefilled:st.nprefilled + take]
             off[st.slot] = st.nprefilled
             nreal[st.slot] = take
+        now = self.now()
+        for st in states:
+            st.note_dispatch(now)
         self._kv.storage = self._chunk(self.params, self._kv.storage,
                                        jnp.asarray(tokens), jnp.asarray(off),
                                        jnp.asarray(nreal), *self._maps())
@@ -1340,8 +1356,10 @@ class ServingEngine:
         batch = np.full((self.n_slots, self.prefill_len), self.pad_id,
                         np.int32)
         states = sch.admit(self.now())
+        now = self.now()
         for i, st in enumerate(states):
             batch[i, :len(st.req.prompt)] = st.req.prompt
+            st.note_dispatch(now)
         _, fresh = self._prefill(self.params, {"tokens":
                                                jnp.asarray(batch)})
         for i, st in enumerate(states):
@@ -1383,8 +1401,18 @@ class ServingEngine:
                 for rid, st in sorted(self._results.items())}
 
     def request_stats(self) -> dict:
-        return {rid: {"ttft_s": st.ttft,
+        """{rid: times and counts} of every admitted request, active or
+        finished, on the engine clock: its ``arrival``, ``t_admit``
+        (slot assigned), ``t_first_dispatch`` (first prompt token
+        dispatched), ``ttft_s`` and ``latency_s`` from arrival (None
+        until reached), and ``tokens`` generated so far."""
+        states = {st.req.rid: st for st in self._sched.active.values()}
+        states.update(self._results)
+        return {rid: {"arrival": st.req.arrival,
+                      "t_admit": st.t_admit,
+                      "t_first_dispatch": st.t_first_dispatch,
+                      "ttft_s": st.ttft,
                       "latency_s": (st.t_finish - st.req.arrival
                                     if st.t_finish is not None else None),
                       "tokens": len(st.generated)}
-                for rid, st in sorted(self._results.items())}
+                for rid, st in sorted(states.items())}

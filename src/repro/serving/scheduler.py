@@ -82,8 +82,8 @@ class RequestState:
     invisible (``col_pos <= pos``) until real decoded tokens land there.
     """
     __slots__ = ("req", "slot", "pos", "next_token", "nprefilled",
-                 "generated", "rng", "t_admit", "ttft", "t_finish",
-                 "restarts", "epoch", "inflight")
+                 "generated", "rng", "t_admit", "t_first_dispatch",
+                 "ttft", "t_finish", "restarts", "epoch", "inflight")
 
     def __init__(self, req: Request, slot: int, t_admit: float):
         self.req = req
@@ -94,6 +94,7 @@ class RequestState:
         self.generated: list = []
         self.rng = req.sampling.make_rng()
         self.t_admit = t_admit
+        self.t_first_dispatch = None   # first prompt token dispatched
         self.ttft = None
         self.t_finish = None
         self.restarts = 0              # re-prefills after a lost restore
@@ -128,6 +129,12 @@ class RequestState:
         self.restarts += 1
         self.epoch += 1                # invalidate in-flight rows
 
+    def note_dispatch(self, t: float):
+        """The engine dispatched prompt tokens of this request at ``t``;
+        the first such time stays (a restart does not reset it)."""
+        if self.t_first_dispatch is None:
+            self.t_first_dispatch = t
+
     def begin_decode(self):
         """Prefill done — rewind to the last prompt token and decode."""
         self.nprefilled = len(self.req.prompt)
@@ -158,6 +165,8 @@ class EngineStats:
     step_latency: deque = field(                       # per decode step (s)
         default_factory=lambda: deque(maxlen=STATS_WINDOW))
     occupancy: deque = field(                          # active/slots per step
+        default_factory=lambda: deque(maxlen=STATS_WINDOW))
+    itl: deque = field(                                # streamed token gaps
         default_factory=lambda: deque(maxlen=STATS_WINDOW))
     prefills: int = 0                  # prefill program calls (flush/chunk)
     prefill_chunks: int = 0            # chunked-mode calls among them
@@ -192,6 +201,7 @@ class EngineStats:
     tokens_streamed: int = 0           # tokens delivered to TokenStreams
     host_busy_s: float = 0.0           # host-side bookkeeping (streaming)
     loop_wall_s: float = 0.0           # total non-idle streaming wall time
+    phase_s: dict = field(default_factory=dict)  # serving.spans name -> s
     t_start: float | None = None
     t_end: float | None = None
 
@@ -251,6 +261,7 @@ class EngineStats:
             # the overlap-efficiency number docs/streaming.md defines
             "host_overhead_fraction": (self.host_busy_s / self.loop_wall_s
                                        if self.loop_wall_s > 0 else 0.0),
+            "phase_s": dict(sorted(self.phase_s.items())),
         }
 
 

@@ -70,6 +70,7 @@ import numpy as np
 
 from ..runtime.serve import make_result_pack
 from .engine import ServingEngine
+from .spans import span, step_span
 
 
 @dataclass
@@ -110,6 +111,10 @@ class TokenStream:
 
     def __init__(self, rid: int):
         self.rid = rid
+        # the engine's RequestState from the first flush after
+        # admission on, for its times (read only: req.arrival,
+        # t_admit, t_first_dispatch, ttft); None before
+        self.state = None
         self._q: deque = deque()
         self._fin: str | None = None
         self._loop = None
@@ -186,7 +191,8 @@ class StreamingEngine:
         self._pipe: deque = deque()    # in-flight ResultTokens, FIFO
         self._streams: dict = {}       # rid -> TokenStream
         self._delivered: dict = {}     # rid -> tokens already pushed
-        self._token_times: dict = {}   # rid -> [engine-time per token]
+        self._last_token: dict = {}    # rid -> engine time of its last
+        self._steps = 0                # loop iterations (profiler steps)
         self._zero = jnp.zeros((engine.n_slots, 4), jnp.int32)
 
     # ------------------------------------------------------------------
@@ -244,14 +250,17 @@ class StreamingEngine:
         retired an in-flight tick, a synchronous-fallback kind, or
         'idle'."""
         t0 = time.perf_counter()
-        kind = self._step_inner()
+        with step_span(self._steps):
+            kind = self._step_inner()
+        self._steps += 1
         if kind != "idle":
             self._eng.stats.loop_wall_s += time.perf_counter() - t0
         return kind
 
     def _step_inner(self) -> str:
         eng = self._eng
-        eng._release_arrivals()
+        with span(eng.stats, "serve.admit"):
+            eng._release_arrivals()
         if eng.stats.t_start is None:
             eng.stats.t_start = eng.now()
         if not self.overlap:
@@ -277,7 +286,8 @@ class StreamingEngine:
             # gather): conservative drain keeps spill/rebind races
             # impossible
             self.drain()
-        eng._admit_or_preempt()
+        with span(eng.stats, "serve.admit"):
+            eng._admit_or_preempt()
         dispatched = None
         if len(self._pipe) < self.depth:
             dispatched = self._dispatch()
@@ -353,63 +363,68 @@ class StreamingEngine:
     def _dispatch_packed(self):
         eng = self._eng
         sch = eng._sched
-        t0 = time.perf_counter()
-        decode, prefill = sch.plan_tick(eng.token_budget)
-        decode = [st for st in decode if self._can_decode(st)]
-        if not decode and not prefill:
-            return None
-        tb = eng.token_budget
-        tok = np.zeros(tb, np.int32)
-        slot = np.full(tb, -1, np.int32)
-        pos = np.full(tb, -1, np.int32)
-        off = np.full(tb, -1, np.int32)
-        pre = np.zeros(tb, np.int32)
-        src = np.full(tb, -1, np.int32)
-        lengths = np.zeros(eng.n_slots, np.int32)
-        records = []
-        i = 0
-        for st in decode:
-            p = st.pos + st.inflight
-            self._spec_token(st, tok, src, i)
-            slot[i] = st.slot
-            pos[i] = off[i] = p
-            lengths[st.slot] = p + 1
-            records.append((st.slot, st, st.epoch))
-            st.inflight += 1
-            i += 1
-        n_dec = i
-        n_prefill = 0
-        for st, take in prefill:
-            o = st.nprefilled
-            tok[i:i + take] = st.req.prompt[o:o + take]
-            slot[i:i + take] = st.slot
-            pos[i:i + take] = np.arange(o, o + take)
-            off[i:i + take] = o
-            pre[i:i + take] = 1
-            i += take
-            n_prefill += take
-            # prefill progress is host-deterministic: advance at
-            # dispatch so the NEXT tick plans past it (the rewind
-            # re-feed token is host-known, so a request can finish
-            # prefill and start decoding with zero pipeline stalls)
-            st.nprefilled += take
-            if not st.prefilling:
-                st.begin_decode()
-        # the packed program's LM head covers the static decode prefix
-        # (min(n_slots, token_budget) rows; decode rows pack first)
-        n_rows = min(eng.n_slots, tb)
-        is_dec = np.zeros(n_rows, np.int32)
-        is_dec[:n_dec] = 1
-        prev_data = self._pipe[-1].data if self._pipe else self._zero
-        eng.stats.host_busy_s += time.perf_counter() - t0
-        tok_dev = self._merge(jnp.asarray(tok), jnp.asarray(src),
-                              prev_data)
-        logits, eng._kv.storage = eng._packed(
-            eng.params, eng._kv.storage, tok_dev, jnp.asarray(slot),
-            jnp.asarray(pos), jnp.asarray(off), jnp.asarray(pre),
-            *eng._maps())
-        data = self._pack(logits, jnp.asarray(slot[:n_rows].copy()),
-                          jnp.asarray(is_dec), jnp.asarray(lengths))
+        with span(eng.stats, "serve.plan"):
+            t0 = time.perf_counter()
+            decode, prefill = sch.plan_tick(eng.token_budget)
+            decode = [st for st in decode if self._can_decode(st)]
+            if not decode and not prefill:
+                return None
+            tb = eng.token_budget
+            tok = np.zeros(tb, np.int32)
+            slot = np.full(tb, -1, np.int32)
+            pos = np.full(tb, -1, np.int32)
+            off = np.full(tb, -1, np.int32)
+            pre = np.zeros(tb, np.int32)
+            src = np.full(tb, -1, np.int32)
+            lengths = np.zeros(eng.n_slots, np.int32)
+            records = []
+            i = 0
+            for st in decode:
+                p = st.pos + st.inflight
+                self._spec_token(st, tok, src, i)
+                slot[i] = st.slot
+                pos[i] = off[i] = p
+                lengths[st.slot] = p + 1
+                records.append((st.slot, st, st.epoch))
+                st.inflight += 1
+                i += 1
+            n_dec = i
+            n_prefill = 0
+            now = eng.now()
+            for st, take in prefill:
+                o = st.nprefilled
+                tok[i:i + take] = st.req.prompt[o:o + take]
+                slot[i:i + take] = st.slot
+                pos[i:i + take] = np.arange(o, o + take)
+                off[i:i + take] = o
+                pre[i:i + take] = 1
+                i += take
+                n_prefill += take
+                st.note_dispatch(now)
+                # prefill progress is host-deterministic: advance at
+                # dispatch so the NEXT tick plans past it (the rewind
+                # re-feed token is host-known, so a request can finish
+                # prefill and start decoding with zero pipeline stalls)
+                st.nprefilled += take
+                if not st.prefilling:
+                    st.begin_decode()
+            # the packed program's LM head covers the static decode
+            # prefix (min(n_slots, token_budget) rows; decode rows pack
+            # first)
+            n_rows = min(eng.n_slots, tb)
+            is_dec = np.zeros(n_rows, np.int32)
+            is_dec[:n_dec] = 1
+            prev_data = self._pipe[-1].data if self._pipe else self._zero
+            eng.stats.host_busy_s += time.perf_counter() - t0
+        with span(eng.stats, "serve.launch"):
+            tok_dev = self._merge(jnp.asarray(tok), jnp.asarray(src),
+                                  prev_data)
+            logits, eng._kv.storage = eng._packed(
+                eng.params, eng._kv.storage, tok_dev, jnp.asarray(slot),
+                jnp.asarray(pos), jnp.asarray(off), jnp.asarray(pre),
+                *eng._maps())
+            data = self._pack(logits, jnp.asarray(slot[:n_rows].copy()),
+                              jnp.asarray(is_dec), jnp.asarray(lengths))
         t1 = time.perf_counter()
         self._push(data, records, "packed")
         eng.stats.packed_ticks += 1
@@ -422,34 +437,36 @@ class StreamingEngine:
     def _dispatch_decode(self):
         eng = self._eng
         sch = eng._sched
-        t0 = time.perf_counter()
-        decode = [st for st in sch.decoding() if self._can_decode(st)]
-        if not decode:
-            return None
-        B = eng.n_slots
-        tok = np.zeros(B, np.int32)
-        pos = np.full(B, -1, np.int32)
-        src = np.full(B, -1, np.int32)
-        is_dec = np.zeros(B, np.int32)
-        lengths = np.zeros(B, np.int32)
-        records = []
-        for st in decode:
-            p = st.pos + st.inflight
-            self._spec_token(st, tok, src, st.slot)
-            pos[st.slot] = p
-            is_dec[st.slot] = 1
-            lengths[st.slot] = p + 1
-            records.append((st.slot, st, st.epoch))
-            st.inflight += 1
-        prev_data = self._pipe[-1].data if self._pipe else self._zero
-        eng.stats.host_busy_s += time.perf_counter() - t0
-        tok_dev = self._merge(jnp.asarray(tok), jnp.asarray(src),
-                              prev_data)
-        logits, eng._kv.storage = eng._step(
-            eng.params, eng._kv.storage, tok_dev, jnp.asarray(pos),
-            *eng._maps())
-        data = self._pack(logits, jnp.arange(B, dtype=jnp.int32),
-                          jnp.asarray(is_dec), jnp.asarray(lengths))
+        with span(eng.stats, "serve.plan"):
+            t0 = time.perf_counter()
+            decode = [st for st in sch.decoding() if self._can_decode(st)]
+            if not decode:
+                return None
+            B = eng.n_slots
+            tok = np.zeros(B, np.int32)
+            pos = np.full(B, -1, np.int32)
+            src = np.full(B, -1, np.int32)
+            is_dec = np.zeros(B, np.int32)
+            lengths = np.zeros(B, np.int32)
+            records = []
+            for st in decode:
+                p = st.pos + st.inflight
+                self._spec_token(st, tok, src, st.slot)
+                pos[st.slot] = p
+                is_dec[st.slot] = 1
+                lengths[st.slot] = p + 1
+                records.append((st.slot, st, st.epoch))
+                st.inflight += 1
+            prev_data = self._pipe[-1].data if self._pipe else self._zero
+            eng.stats.host_busy_s += time.perf_counter() - t0
+        with span(eng.stats, "serve.launch"):
+            tok_dev = self._merge(jnp.asarray(tok), jnp.asarray(src),
+                                  prev_data)
+            logits, eng._kv.storage = eng._step(
+                eng.params, eng._kv.storage, tok_dev, jnp.asarray(pos),
+                *eng._maps())
+            data = self._pack(logits, jnp.arange(B, dtype=jnp.int32),
+                              jnp.asarray(is_dec), jnp.asarray(lengths))
         t1 = time.perf_counter()
         self._push(data, records, "decode")
         eng.stats.decode_steps += 1
@@ -472,25 +489,27 @@ class StreamingEngine:
     def _reconcile_one(self) -> None:
         eng = self._eng
         tick = self._pipe.popleft()
-        data = tick.get()              # THE host copy; blocks on device
-        t0 = time.perf_counter()
-        now = eng.now()
-        eng.stats.step_latency.append(now - tick.t_dispatch)
-        for slot, st, epoch in tick.records:
-            st.inflight -= 1
-            if st.epoch != epoch:
-                continue               # rewound (quarantine/restart)
-            if eng._sched.active.get(slot) is not st:
-                continue               # evicted / preempted / cancelled
-            token, valid, _, finite = data[slot]
-            if not valid:
-                continue
-            if eng._nan_guard and not finite:
-                eng._quarantine(st)    # bumps epoch: later rows stale
-                continue
-            eng._advance_token(st, int(token), now)
-        eng.stats.t_end = eng.now()
-        eng.stats.host_busy_s += time.perf_counter() - t0
+        with span(eng.stats, "serve.wait"):
+            data = tick.get()          # THE host copy; blocks on device
+        with span(eng.stats, "serve.reconcile"):
+            t0 = time.perf_counter()
+            now = eng.now()
+            eng.stats.step_latency.append(now - tick.t_dispatch)
+            for slot, st, epoch in tick.records:
+                st.inflight -= 1
+                if st.epoch != epoch:
+                    continue           # rewound (quarantine/restart)
+                if eng._sched.active.get(slot) is not st:
+                    continue           # evicted / preempted / cancelled
+                token, valid, _, finite = data[slot]
+                if not valid:
+                    continue
+                if eng._nan_guard and not finite:
+                    eng._quarantine(st)  # bumps epoch: later rows stale
+                    continue
+                eng._advance_token(st, int(token), now)
+            eng.stats.t_end = eng.now()
+            eng.stats.host_busy_s += time.perf_counter() - t0
 
     def _deadline_due(self) -> bool:
         now = self._eng.now()
@@ -504,46 +523,55 @@ class StreamingEngine:
         eng = self._eng
         if not self._streams:
             return
-        done = []
-        now = eng.now()
-        for rid, stream in self._streams.items():
-            fin = None
-            st = eng._results.get(rid)
-            if st is not None:
-                fin = ("eos" if (st.req.eos_id is not None
-                                 and st.generated
-                                 and st.generated[-1] == st.req.eos_id)
-                       else "length")
-            elif rid in eng._failed:
-                fin = eng._failed[rid]
-                st = None
-            else:
-                st = next((s for s in eng._sched.active.values()
-                           if s.req.rid == rid), None)
-            if st is not None:
-                sent = self._delivered.get(rid, 0)
-                fresh = st.generated[sent:]
-                if fresh:
-                    for t in fresh:
-                        stream.put(int(t))
-                    self._delivered[rid] = sent + len(fresh)
-                    eng.stats.tokens_streamed += len(fresh)
-                    self._token_times.setdefault(rid, []).extend(
-                        [now] * len(fresh))
-            if fin is not None:
-                stream.finish(fin)
-                done.append(rid)
-        for rid in done:
-            del self._streams[rid]
-            self._delivered.pop(rid, None)
+        with span(eng.stats, "serve.flush"):
+            done = []
+            now = eng.now()
+            for rid, stream in self._streams.items():
+                fin = None
+                st = eng._results.get(rid)
+                if st is not None:
+                    fin = ("eos" if (st.req.eos_id is not None
+                                     and st.generated
+                                     and st.generated[-1] == st.req.eos_id)
+                           else "length")
+                elif rid in eng._failed:
+                    fin = eng._failed[rid]
+                    st = None
+                else:
+                    st = next((s for s in eng._sched.active.values()
+                               if s.req.rid == rid), None)
+                if st is not None:
+                    stream.state = st
+                    sent = self._delivered.get(rid, 0)
+                    fresh = st.generated[sent:]
+                    if fresh:
+                        for t in fresh:
+                            stream.put(int(t))
+                        self._delivered[rid] = sent + len(fresh)
+                        eng.stats.tokens_streamed += len(fresh)
+                        self._note_itl(rid, now, len(fresh))
+                if fin is not None:
+                    stream.finish(fin)
+                    done.append(rid)
+            for rid in done:
+                del self._streams[rid]
+                self._delivered.pop(rid, None)
+                self._last_token.pop(rid, None)
 
-    def itl_samples(self) -> dict:
-        """{rid: [inter-token latencies]} in engine-clock units, from
-        the stream delivery timestamps (tokens delivered in the same
-        flush contribute zero — they arrived in one reconcile)."""
-        return {rid: [b - a for a, b in zip(ts, ts[1:])]
-                for rid, ts in self._token_times.items()
-                if len(ts) > 1}
+    def _note_itl(self, rid: int, now: float, n: int) -> None:
+        """Record the gaps before ``n`` tokens of ``rid`` delivered at
+        ``now``: the gap since its previous delivery, then zeros (they
+        arrived in one reconcile)."""
+        last = self._last_token.get(rid)
+        gaps = ([now - last] if last is not None else []) + [0.0] * (n - 1)
+        self._eng.stats.itl.extend(gaps)
+        self._last_token[rid] = now
+
+    def itl_samples(self) -> list:
+        """Inter-token latencies of streamed requests in engine-clock
+        units, from the stream delivery timestamps: the most recent
+        ``STATS_WINDOW`` gaps (``EngineStats.itl``)."""
+        return list(self._eng.stats.itl)
 
 
 async def serve_stream(seng: StreamingEngine, requests: list,
