@@ -1,5 +1,9 @@
 """Output tokens delivered to their streams in the window, over the
-window's seconds."""
+window's seconds.
+
+Reported only where the offered load exceeds what the engine serves (a
+closed loop, or an open loop above the knee): below the knee it reads
+the offered rate shifted by the engine's lag."""
 
 
 def read(v):
